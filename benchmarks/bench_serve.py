@@ -107,6 +107,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch import compile_cache
 from repro.launch.engine import Engine, EngineConfig, SamplingParams
 from repro.launch.engine.api import latency_stats
 from repro.models.model import Model
@@ -1093,6 +1094,7 @@ def run():
 
 def main():
     args = _parser().parse_args()
+    compile_cache.enable()
     _emit(run_bench(args), args.json)
 
 

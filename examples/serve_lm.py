@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch import compile_cache
 from repro.launch.engine import (DisaggregatedEngine, Engine, EngineConfig,
                                  ReplicaSet, SamplingParams)
 from repro.models.model import Model
@@ -72,6 +73,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny trace for CI")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.smoke:
         args.requests, args.n_new = 6, 8
 

@@ -22,9 +22,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import TPUCompilerParams
-
-MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
+# Kernel constants are typed f32/i32: with jax_enable_x64 on, a Python
+# float passed to jnp.where becomes an f64 operand and a Python int in a
+# floor division or an index map an i64 one, which Mosaic cannot lower.
+MASK_VALUE = np.float32(-0.7 * np.finfo(np.float32).max)
+ZERO = np.float32(0.0)
+ONE = np.float32(1.0)
+I0 = np.int32(0)
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -69,7 +73,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)                    # (bq, bk)
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(mask, p, ZERO)
         corr = jnp.exp(m_prev - m_new)            # (bq, 1)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
@@ -79,7 +83,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(kj == nk - 1)
     def _store():
         l = l_ref[...]
-        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        out = acc_ref[...] / jnp.where(l == ZERO, ONE, l)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -93,18 +97,18 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     group = Hq // Hkv
-    # python float (weak type): np.float64 would promote f32 math to f64
-    # when x64 is enabled.
-    scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
+    scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(D))
     kv_len = Skv if kv_len is None else kv_len
     qr = q.reshape(B * Hq, Sq, D)
     kr = k.reshape(B * Hkv, Skv, D)
     vr = v.reshape(B * Hkv, Skv, D)
     grid = (B * Hq, Sq // block_q, Skv // block_k)
 
+    hq, hkv, grp = np.int32(Hq), np.int32(Hkv), np.int32(group)
+
     def kv_map(bh, qi, kj):
-        b, h = bh // Hq, bh % Hq
-        return b * Hkv + h // group, kj, 0
+        b, h = bh // hq, bh % hq
+        return b * hkv + h // grp, kj, I0
 
     out = pl.pallas_call(
         functools.partial(_fa_kernel, scale=scale, causal=causal,
@@ -112,18 +116,19 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
                           block_k=block_k, nk=grid[2]),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, I0)),
             pl.BlockSpec((1, block_k, D), kv_map),
             pl.BlockSpec((1, block_k, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, block_q, D),
+                               lambda bh, qi, kj: (bh, qi, I0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

@@ -48,6 +48,53 @@ def _use_pallas(mode: str) -> tuple[bool, bool]:
     raise ValueError(f"unknown kernel mode {mode!r}")
 
 
+def _ref_grad(fast, ref):
+    """``fast``'s forward with the gradient of ``ref`` (same math):
+    the Pallas kernels have no backward pass, so training differentiates
+    the jnp reference instead."""
+    f = jax.custom_vjp(fast)
+    f.defvjp(lambda *args: (fast(*args), args),
+             lambda args, g: jax.vjp(ref, *args)[1](g))
+    return f
+
+
+def _per_shard(fn, sharding, dims):
+    """``fn`` run shard by shard on ``sharding``'s mesh (a ``ShardCtx``),
+    or ``fn`` itself when ``sharding`` is None. GSPMD cannot partition a
+    Mosaic kernel, so under a mesh a Pallas call needs a shard_map.
+
+    ``dims`` gives each operand's dims by role: ``"b"`` splits over the
+    batch axes and ``"h"`` (heads, channels) over the tp axis, each only
+    where the mesh divides that dim in every operand; ``None`` stays
+    whole. The output takes the first operand's spec.
+    """
+    if sharding is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    mesh = sharding.mesh
+    split = {"b": tuple(sharding.batch_axes),
+             "h": () if sharding.tp_axis in sharding.batch_axes
+             else (sharding.tp_axis,)}
+    width = {r: int(np.prod([mesh.shape[a] for a in ax]))
+             for r, ax in split.items()}
+
+    def run(*args):
+        ok = {r: bool(ax) for r, ax in split.items()}
+        for d, x in zip(dims, args):
+            for role, n in zip(d, x.shape):
+                if role is not None and n % width[role]:
+                    ok[role] = False
+        specs = tuple(P(*(split[r] if r is not None and ok[r] else None
+                          for r in d)) for d in dims[:len(args)])
+        # check_vma=False: pallas_call's out_shape carries no per-axis
+        # variance, which the checked shard_map would demand
+        return jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                             out_specs=specs[0], check_vma=False)(*args)
+
+    return run
+
+
 def _pad_to(x, axis, mult):
     n = x.shape[axis]
     pad = (-n) % mult
@@ -110,20 +157,30 @@ def stencil3d(x, weights, *, block_d=8, block_m=32, block_n=128, mode="auto",
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
-                    block_q=128, block_k=128, mode="auto", interpret=False):
-    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D)."""
+                    block_q=128, block_k=128, mode="auto", interpret=False,
+                    sharding=None):
+    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D). Under ``sharding`` (a
+    ``ShardCtx``) the kernel runs per batch and head shard."""
     use, interp = _use_pallas(mode)
     interp = interp or interpret
+    ref = functools.partial(_ref.flash_attention, causal=causal,
+                            window=window, scale=scale)
     if not use and not interp:
-        return _ref.flash_attention(q, k, v, causal=causal, window=window,
-                                    scale=scale)
-    qp, sq0 = _pad_to(q, 2, block_q)
-    kp, skv0 = _pad_to(k, 2, block_k)
-    vp, _ = _pad_to(v, 2, block_k)
-    out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
-                                 scale=scale, kv_len=skv0, block_q=block_q,
-                                 block_k=block_k, interpret=interp)
-    return out[:, :, :sq0]
+        return ref(q, k, v)
+
+    def fast(q, k, v):
+        qp, sq0 = _pad_to(q, 2, block_q)
+        kp, skv0 = _pad_to(k, 2, block_k)
+        vp, _ = _pad_to(v, 2, block_k)
+        out = flash_attention_pallas(
+            qp, kp, vp, causal=causal, window=window, scale=scale,
+            kv_len=skv0, block_q=block_q, block_k=block_k,
+            interpret=interp)
+        return out[:, :, :sq0]
+
+    bhsd = ("b", "h", None, None)
+    fast = _per_shard(fast, sharding, (bhsd,) * 3)
+    return _ref_grad(fast, ref)(q, k, v)
 
 
 def paged_attention(q, pool, block_table, lengths, *, mode="decode",
@@ -308,23 +365,32 @@ def vrp_sum(x, *, mode="auto", interpret=False):
 
 
 def rglru_scan(a, x, h0=None, *, block_b=8, block_t=128, block_d=128,
-               mode="auto", interpret=False):
-    """Diagonal linear recurrence h_t = a_t h_{t-1} + x_t; (B, T, D)."""
+               mode="auto", interpret=False, sharding=None):
+    """Diagonal linear recurrence h_t = a_t h_{t-1} + x_t; (B, T, D).
+    Under ``sharding`` (a ``ShardCtx``) the kernel runs per batch and
+    channel shard."""
     use, interp = _use_pallas(mode)
     interp = interp or interpret
     if not use and not interp:
         return _ref.linear_scan(a, x, h0)
-    B, T, D = x.shape
-    ap, b0 = _pad_to(a, 0, block_b)
-    xp, _ = _pad_to(x, 0, block_b)
-    ap, t0 = _pad_to(ap, 1, block_t)
-    xp, _ = _pad_to(xp, 1, block_t)
-    ap, d0 = _pad_to(ap, 2, block_d)
-    xp, _ = _pad_to(xp, 2, block_d)
-    h0p = None
-    if h0 is not None:
-        h0p, _ = _pad_to(h0, 0, block_b)
-        h0p, _ = _pad_to(h0p, 1, block_d)
-    out = rglru_scan_pallas(ap, xp, h0p, block_b=block_b, block_t=block_t,
-                            block_d=block_d, interpret=interp)
-    return out[:b0, :t0, :d0]
+
+    def fast(a, x, *h0):
+        ap, b0 = _pad_to(a, 0, block_b)
+        xp, _ = _pad_to(x, 0, block_b)
+        ap, t0 = _pad_to(ap, 1, block_t)
+        xp, _ = _pad_to(xp, 1, block_t)
+        ap, d0 = _pad_to(ap, 2, block_d)
+        xp, _ = _pad_to(xp, 2, block_d)
+        h0p = None
+        if h0:
+            h0p, _ = _pad_to(h0[0], 0, block_b)
+            h0p, _ = _pad_to(h0p, 1, block_d)
+        out = rglru_scan_pallas(ap, xp, h0p, block_b=block_b,
+                                block_t=block_t, block_d=block_d,
+                                interpret=interp)
+        return out[:b0, :t0, :d0]
+
+    btd = ("b", None, "h")
+    fast = _per_shard(fast, sharding, (btd, btd, ("b", "h")))
+    h0s = () if h0 is None else (h0,)
+    return _ref_grad(fast, _ref.linear_scan)(a, x, *h0s)

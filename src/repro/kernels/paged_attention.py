@@ -29,14 +29,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import TPUCompilerParams
-
-from .flash_attention import MASK_VALUE
+from .flash_attention import I0, MASK_VALUE, ONE, ZERO
 
 # Megacore work split: the batch axis is embarrassingly parallel (each
 # sequence owns its own online-softmax scratch); the kv-block axis is
 # sequential by construction. Interpret mode ignores compiler params.
-_MEGACORE = TPUCompilerParams(
+_MEGACORE = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
@@ -98,7 +96,7 @@ def _pa_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
         m_prev = m_ref[...]                             # (Hq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(mask, p, ZERO)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         pg = p.reshape(hkv, group, block_size)
@@ -110,7 +108,7 @@ def _pa_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
     @pl.when(i == nb - 1)
     def _store():
         l = l_ref[...]
-        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        out = acc_ref[...] / jnp.where(l == ZERO, ONE, l)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -126,17 +124,17 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, lengths, *,
     _, BS, Hkv, _ = k_pool.shape
     group = Hq // Hkv
     nbmax = block_table.shape[1]
-    scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
+    scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(D))
     quantized = k_scale is not None
 
     def kv_map(b, i, bt, lens):
-        return (bt[b, i], 0, 0, 0)
+        return (bt[b, i], I0, I0, I0)
 
     def scale_map(b, i, bt, lens):
-        return (bt[b, i], 0, 0)
+        return (bt[b, i], I0, I0)
 
     in_specs = [
-        pl.BlockSpec((1, Hq, D), lambda b, i, bt, lens: (b, 0, 0)),
+        pl.BlockSpec((1, Hq, D), lambda b, i, bt, lens: (b, I0, I0)),
         pl.BlockSpec((1, BS, Hkv, D), kv_map),
         pl.BlockSpec((1, BS, Hkv, D), kv_map),
     ]
@@ -148,7 +146,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, lengths, *,
         num_scalar_prefetch=2,
         grid=(B, nbmax),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, i, bt, lens: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, i, bt, lens: (b, I0, I0)),
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),    # running max
             pltpu.VMEM((Hq, 1), jnp.float32),    # running sum
@@ -215,7 +213,7 @@ def _pv_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
                                                (k1 * hq, block_size), 1)
         # row j of the q-block attends positions < length + 1 + j
         j = jax.lax.broadcasted_iota(jnp.int32,
-                                     (k1 * hq, block_size), 0) // hq
+                                     (k1 * hq, block_size), 0) // np.int32(hq)
         limit = length + 1 + j
         mask = kpos < limit
         if window is not None:
@@ -225,7 +223,7 @@ def _pv_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
         m_prev = m_ref[...]                             # (K1*Hq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(mask, p, ZERO)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         pg = p.reshape(k1, hkv, group, block_size).transpose(1, 0, 2, 3) \
@@ -240,7 +238,7 @@ def _pv_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
     @pl.when(i == nb - 1)
     def _store():
         l = l_ref[...]
-        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        out = acc_ref[...] / jnp.where(l == ZERO, ONE, l)
         o_ref[0] = out.reshape(k1, hkv * group, -1).astype(o_ref.dtype)
 
 
@@ -268,17 +266,18 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, block_table, lengths,
     _, BS, Hkv, _ = k_pool.shape
     group = Hq // Hkv
     nbmax = block_table.shape[1]
-    scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
+    scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(D))
     quantized = k_scale is not None
 
     def kv_map(b, i, bt, lens):
-        return (bt[b, i], 0, 0, 0)
+        return (bt[b, i], I0, I0, I0)
 
     def scale_map(b, i, bt, lens):
-        return (bt[b, i], 0, 0)
+        return (bt[b, i], I0, I0)
 
     in_specs = [
-        pl.BlockSpec((1, K1, Hq, D), lambda b, i, bt, lens: (b, 0, 0, 0)),
+        pl.BlockSpec((1, K1, Hq, D),
+                     lambda b, i, bt, lens: (b, I0, I0, I0)),
         pl.BlockSpec((1, BS, Hkv, D), kv_map),
         pl.BlockSpec((1, BS, Hkv, D), kv_map),
     ]
@@ -291,7 +290,7 @@ def paged_verify_attention_pallas(q, k_pool, v_pool, block_table, lengths,
         grid=(B, nbmax),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, K1, Hq, D),
-                               lambda b, i, bt, lens: (b, 0, 0, 0)),
+                               lambda b, i, bt, lens: (b, I0, I0, I0)),
         scratch_shapes=[
             pltpu.VMEM((K1 * Hq, 1), jnp.float32),    # running max
             pltpu.VMEM((K1 * Hq, 1), jnp.float32),    # running sum
@@ -330,8 +329,6 @@ def paged_verify_attention_headshard(q, k_pool, v_pool, block_table,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
-
     if attend is None:
         attend = functools.partial(paged_verify_attention_pallas,
                                    interpret=interpret)
@@ -352,9 +349,11 @@ def paged_verify_attention_headshard(q, k_pool, v_pool, block_table,
             return attend(qv, kp, vp, bt, ln, window=window, scale=scale,
                           k_scale=ks, v_scale=vs)
 
-    return shard_map(
+    # check_vma=False: pallas_call's out_shape carries no per-axis
+    # variance, which the checked shard_map would demand
+    return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs,
-        out_specs=P(None, None, tp, None),
+        out_specs=P(None, None, tp, None), check_vma=False,
     )(*operands)
 
 
@@ -384,8 +383,6 @@ def paged_decode_attention_headshard(q, k_pool, v_pool, block_table,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
-
     if attend is None:
         attend = functools.partial(paged_decode_attention_pallas,
                                    interpret=interpret)
@@ -406,7 +403,9 @@ def paged_decode_attention_headshard(q, k_pool, v_pool, block_table,
             return attend(qv, kp, vp, bt, ln, window=window, scale=scale,
                           k_scale=ks, v_scale=vs)
 
-    return shard_map(
+    # check_vma=False: pallas_call's out_shape carries no per-axis
+    # variance, which the checked shard_map would demand
+    return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs,
-        out_specs=P(None, tp, None),
+        out_specs=P(None, tp, None), check_vma=False,
     )(*operands)

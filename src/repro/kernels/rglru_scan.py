@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import TPUCompilerParams
-
 
 def _scan_kernel(a_ref, x_ref, h0_ref, o_ref, h_ref, *, block_t: int):
     tj = pl.program_id(2)
@@ -63,7 +61,7 @@ def rglru_scan_pallas(a, x, h0=None, *, block_b=8, block_t=128, block_d=128,
                                lambda i, j, t: (i, t, j)),
         out_shape=jax.ShapeDtypeStruct((B, T, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_d), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x, h0)

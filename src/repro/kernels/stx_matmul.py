@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import TPUCompilerParams
-
 
 def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     k = pl.program_id(2)
@@ -60,7 +58,7 @@ def stx_matmul_pallas(x, w, *, block_m=128, block_n=128, block_k=128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -24,14 +24,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _halo_spec(block_shape, index_map):
-    """Element-indexed BlockSpec across jax generations: newer pallas
-    spells it per-dimension (`pl.Element`); 0.4.x spells it as an
-    ``Unblocked`` indexing mode on the whole spec."""
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(b) for b in block_shape),
-                            index_map)
-    return pl.BlockSpec(tuple(block_shape), index_map,
-                        indexing_mode=pl.unblocked)
+    """Element-indexed BlockSpec: every dimension indexes elements, not
+    blocks, so the map can address a tile plus its halo."""
+    return pl.BlockSpec(tuple(pl.Element(b) for b in block_shape),
+                        index_map)
 
 
 def _st2d_kernel(w_ref, x_ref, o_ref):

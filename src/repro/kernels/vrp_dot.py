@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import TPUCompilerParams
-
 from repro.core.vrp import two_prod, two_sum
 
 _F32_SPLITTER = float(2**12 + 1)
@@ -79,7 +77,7 @@ def vrp_dot_pallas(x, y, *, interpret=False):
             pltpu.VMEM((8, 128), jnp.float32),
             pltpu.VMEM((8, 128), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xr, yr)[0]
@@ -116,7 +114,7 @@ def vrp_sum_pallas(x, *, interpret=False):
             pltpu.VMEM((8, 128), jnp.float32),
             pltpu.VMEM((8, 128), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x.reshape(nb, 8, 128))[0]

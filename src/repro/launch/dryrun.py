@@ -32,7 +32,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.configs import ARCH_IDS, LM_SHAPES, get_cell, get_config
 from repro.launch import sharding as shlib
 from repro.launch.mesh import make_production_mesh, mesh_summary
@@ -175,7 +174,7 @@ def _cell_costs(cfg, cell, mesh, n_dev, pod_size, remat,
     with mesh:
         fn, args = build(cfg, cell, mesh, remat=remat, unroll=True)
         compiled = fn.lower(*args).compile()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
     coll = ra.parse_collectives(hlo, pod_size=pod_size, n_devices=n_dev)
     return (float(cost.get("flops", 0.0)),
@@ -260,7 +259,7 @@ def run_cell(arch: str, cell_name: str, multi_pod: bool, remat="full",
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         print(mem)                      # proves it fits (spec step 3)
-        print({k: v for k, v in compat.cost_analysis(compiled).items()
+        print({k: v for k, v in compiled.cost_analysis().items()
                if k in ("flops", "bytes accessed")})
     # Depth-corrected roofline inputs (scan trip-count fix).
     flops_dev, bytes_dev, coll = depth_corrected_costs(

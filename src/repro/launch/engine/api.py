@@ -443,7 +443,8 @@ class Engine:
     cfg : EngineConfig, optional
         Backend selection and geometry; defaults to ``EngineConfig()``.
     ctx : RunCtx, optional
-        Kernel/sharding context; defaults to the jnp reference kernels.
+        Kernel/sharding context; defaults to ``kernel_mode="auto"``:
+        the Pallas kernels on a TPU, the jnp references elsewhere.
 
     Attributes
     ----------
@@ -522,7 +523,7 @@ class Engine:
                     f"{self.cfg.kv_dtype!r}): ServingCaps.quantized_kv "
                     "is False — encoder-decoder cross-KV arenas and "
                     "non-paged frontends stay bf16")
-        ctx = ctx or RunCtx(kernel_mode="ref")
+        ctx = ctx or RunCtx(kernel_mode="auto")
         if self.cfg.mesh is not None and ctx.shard is None:
             from repro.launch.sharding import make_shard_ctx
             from repro.models.paged_kv import head_shard_ok

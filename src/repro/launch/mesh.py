@@ -12,18 +12,27 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: GSPMD propagates shardings and
+    the jits need no mesh context, as every caller here expects (the
+    default Explicit axes refuse ambiguous contractions and jits
+    outside ``with mesh``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh over available devices (tests, small runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(tp: int = 1):
@@ -38,7 +47,7 @@ def make_local_mesh(tp: int = 1):
             f"--tp {tp} must be >= 1 and divide the local device count "
             f"({n}); fake devices with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return _auto_mesh((n // tp, tp), ("data", "model"))
 
 
 def dp_axes_of(mesh) -> tuple:

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import pvary, shard_map
-
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh,
                    axis: str = "pipe"):
@@ -66,18 +64,17 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh,
                 y, axis, [(i, (i + 1) % S) for i in range(S)])
             return (nxt, outputs), None
 
-        # pvary: the carry becomes device-varying after the first tick
-        # (jax >= 0.8 checks manual-axis variance of scan carries;
-        # identity on older jax — see core/compat.py)
-        zero = pvary(jnp.zeros_like(xs[0]), axis)
-        outs0 = pvary(jnp.zeros_like(xs), axis)
+        # the carry becomes device-varying after the first tick, and
+        # shard_map checks manual-axis variance of scan carries
+        zero = jax.lax.pcast(jnp.zeros_like(xs[0]), axis, to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), axis, to="varying")
         (_, outputs), _ = jax.lax.scan(
             tick, (zero, outs0), jnp.arange(n_ticks))
         # only the last stage holds real outputs; broadcast them
         outputs = jnp.where(me == S - 1, outputs, jnp.zeros_like(outputs))
         return jax.lax.psum(outputs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch import compile_cache
 from repro.launch.engine import (DisaggregatedEngine, Engine, EngineConfig,
                                  ReplicaSet, SamplingParams)
 from repro.launch.mesh import replica_cli_mesh
@@ -65,6 +66,7 @@ def main():
                          "scales with dequant fused into the kernels "
                          "(paged backend only)")
     args = ap.parse_args()
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

@@ -216,7 +216,7 @@ def main():
         cfg = cfg.smoke()
     model = Model(cfg)
     opt_cfg = OptConfig(kahan=args.kahan, grad_accum=args.grad_accum)
-    ctx = RunCtx(kernel_mode="ref")
+    ctx = RunCtx(kernel_mode="auto")
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                           global_batch=args.batch)
     mesh = make_local_mesh(tp=args.tp) if len(jax.devices()) > 1 else None

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import shard_map
 from repro.kernels import ops as kops
 from repro.models import layers
 
@@ -57,8 +56,9 @@ def _project_qkv(params, cfg, xq, xkv):
 
 
 def attend(params, cfg, x, positions, *, window=None, causal=True,
-           mrope_positions=None, kernel_mode="auto"):
-    """Full-sequence (train / prefill) self-attention. x: (B, S, d)."""
+           mrope_positions=None, kernel_mode="auto", shard=None):
+    """Full-sequence (train / prefill) self-attention. x: (B, S, d).
+    ``shard``: the ``ShardCtx`` the kernel is split over, or None."""
     q, k, v = _project_qkv(params, cfg, x, x)
     if cfg.rope_style == "mrope":
         q = layers.apply_mrope(q, mrope_positions, cfg.mrope_sections, cfg.rope_theta)
@@ -69,7 +69,7 @@ def attend(params, cfg, x, positions, *, window=None, causal=True,
     out = kops.flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=causal, window=window,
-        mode=kernel_mode)
+        mode=kernel_mode, sharding=shard)
     B, S, _ = x.shape
     out = out.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.head_dim)
     return out @ params["wo"]
@@ -448,7 +448,7 @@ def decode_attend_seqshard(params, cfg, x, cache, pos, shard,
         out = out.reshape(B_l, 1, hq * hd).astype(x.dtype)
         return out, ck, cv
 
-    out, ck, cv = shard_map(
+    out, ck, cv = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, None, None, None),
                   P(dp, None, None, None),

@@ -77,7 +77,8 @@ def encode(params, cfg: ModelConfig, frames, ctx: RunCtx,
         if enc_lengths is None:
             out = attn_lib.attend(p["attn"], cfg, xn,
                                   jnp.arange(xn.shape[1]), causal=False,
-                                  kernel_mode=ctx.kernel_mode)
+                                  kernel_mode=ctx.kernel_mode,
+                                  shard=ctx.shard)
         else:
             out = attn_lib.attend_masked(p["attn"], cfg, xn, enc_lengths)
         xc = xc + out
@@ -95,7 +96,7 @@ def _dec_block(p, cfg, x, positions, cross_kv, ctx, self_cache=None,
     xn = layers.apply_norm(cfg.norm, p["ln1"], x)
     if self_cache is None:
         x = x + attn_lib.attend(p["attn"], cfg, xn, positions, causal=True,
-                                kernel_mode=ctx.kernel_mode)
+                                kernel_mode=ctx.kernel_mode, shard=ctx.shard)
         new_cache = None
     else:
         out, new_cache = attn_lib.decode_attend(p["attn"], cfg, xn,
@@ -166,7 +167,8 @@ def prefill(params, cfg: ModelConfig, tokens, frames, ctx: RunCtx,
         out = kops.flash_attention(q.transpose(0, 2, 1, 3),
                                    k.transpose(0, 2, 1, 3),
                                    v.transpose(0, 2, 1, 3), causal=True,
-                                   mode=ctx.kernel_mode)
+                                   mode=ctx.kernel_mode,
+                                   sharding=ctx.shard)
         B = xc.shape[0]
         out = out.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.head_dim)
         xc = xc + out @ p["attn"]["wo"]
@@ -293,7 +295,8 @@ def prefill_paged(params, cfg: ModelConfig, pools, tokens, frames,
         out = kops.flash_attention(q.transpose(0, 2, 1, 3),
                                    k.transpose(0, 2, 1, 3),
                                    v.transpose(0, 2, 1, 3), causal=True,
-                                   mode=ctx.kernel_mode)
+                                   mode=ctx.kernel_mode,
+                                   sharding=ctx.shard)
         out = out.transpose(0, 2, 1, 3).reshape(
             N, Sb, cfg.n_heads * cfg.head_dim)
         xc = xc + out @ p["attn"]["wo"]

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compat import shard_map
-
 
 def truncated_normal_init(key, shape, dtype, stddev=None):
     stddev = stddev if stddev is not None else 1.0 / math.sqrt(shape[0])
@@ -210,7 +208,7 @@ def vocab_parallel_lookup(table, tokens, shard):
 
     dp = shard.dp_axes
     batch_axes = dp if tokens.shape[0] % shard.dp_size == 0 else None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=shard.mesh,
         in_specs=(P(tp, None), P(batch_axes, None)),
         out_specs=P(batch_axes, None, None))(table, tokens)

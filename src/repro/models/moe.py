@@ -25,7 +25,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import shard_map
 from repro.models import layers
 
 
@@ -225,7 +224,7 @@ def apply_moe_sharded(params, cfg, x, shard, mode: str = "gather",
         return out.reshape(B_l, S_l, d).astype(x_l.dtype), aux_loss(load, imp)
 
     local_fn = local_gather if mode == "gather" else local_partial
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P(tp, dp, None), P(tp, dp, None), P(tp, None, dp)),

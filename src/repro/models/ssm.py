@@ -339,13 +339,14 @@ def _rglru_coeffs(params, y):
     return a, beta * (i * yf)
 
 
-def apply_rglru_block(params, cfg, xn, kernel_mode="auto"):
+def apply_rglru_block(params, cfg, xn, kernel_mode="auto", shard=None):
     """Full-sequence Griffin recurrent mixing. Returns delta."""
     gate = jax.nn.gelu(xn @ params["w_gate"], approximate=True)
     xb = xn @ params["w_x"]
     y, _ = layers.apply_conv1d(params["conv"], xb)
     a, b = _rglru_coeffs(params, y)
-    h = kops.rglru_scan(a, b, mode=kernel_mode).astype(xn.dtype)
+    h = kops.rglru_scan(a, b, mode=kernel_mode,
+                        sharding=shard).astype(xn.dtype)
     return (gate * h) @ params["w_out"]
 
 

@@ -153,7 +153,8 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, positions, ctx: RunCtx,
             out = attn_lib.attend(p["attn"], cfg, xn, positions,
                                   window=window, causal=ctx.causal,
                                   mrope_positions=mrope_positions,
-                                  kernel_mode=ctx.kernel_mode)
+                                  kernel_mode=ctx.kernel_mode,
+                                  shard=ctx.shard)
         x = _constrain_residual(x + out, ctx)
         x, aux = _ffn_part(p, cfg, x, ctx, dropless=with_cache)
         return x, aux, cache
@@ -163,7 +164,8 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, positions, ctx: RunCtx,
                                            prefill_length)
         else:
             out = ssm.apply_rglru_block(p["rec"], cfg, xn,
-                                        kernel_mode=ctx.kernel_mode)
+                                        kernel_mode=ctx.kernel_mode,
+                                        shard=ctx.shard)
         x = _constrain_residual(x + out, ctx)
         x, aux = _ffn_part(p, cfg, x, ctx, dropless=with_cache)
         return x, aux, cache
@@ -207,7 +209,7 @@ def _attend_with_cache(params, cfg, xn, positions, window, ctx,
     out = __import__("repro.kernels.ops", fromlist=["x"]).flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=True, window=window,
-        mode=ctx.kernel_mode)
+        mode=ctx.kernel_mode, sharding=ctx.shard)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.head_dim)
     out = out @ params["wo"]
     size = min(window, cache_len or S) if window else (cache_len or S)
@@ -237,7 +239,7 @@ def _rglru_with_cache(params, cfg, xn, ctx, length=None):
     y, conv_state = layers.apply_conv1d(params["conv"], xb)
     a, b = ssm._rglru_coeffs(params, y)
     h = __import__("repro.kernels.ops", fromlist=["x"]).rglru_scan(
-        a, b, mode=ctx.kernel_mode)
+        a, b, mode=ctx.kernel_mode, sharding=ctx.shard)
     out = (gate * h.astype(xn.dtype)) @ params["w_out"]
     if length is None:
         return out, {"h": h[:, -1].astype(jnp.float32), "conv": conv_state}
@@ -278,14 +280,17 @@ def _slstm_with_cache(params, cfg, xn, length=None):
     x_parts = xn @ params["w_zifo"]
     state = (jnp.zeros((B, H, hd), jnp.float32),) * 3 + (
         jnp.full((B, H, hd), -1e30, jnp.float32),)
+    if length is None:
+        length = jnp.full((B,), S, jnp.int32)
 
     def step(st, inp):
         xp, t = inp
         hidden, st_new = ssm._slstm_cell(params, cfg, xp, st)
-        if length is None:
-            return st_new, hidden
         # Right-padded prefill: keep the pre-step carry on pad rows so
         # the final state is frozen bit-exactly at each true length.
+        # Exact-length prefill takes the same select: without it the step
+        # compiles differently (fused multiply-adds), and the two prefills
+        # would then disagree in the last bits.
         keep = (t < length)[:, None, None]
         st = tuple(jnp.where(keep, new, old)
                    for new, old in zip(st_new, st))
@@ -622,7 +627,11 @@ def _apply_groups(params, cfg, x, positions, ctx, mrope_positions=None,
 
 def _logits(params, cfg, x):
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return (x @ head).astype(jnp.float32)
+    # f32 straight from the matmul. A bf16 product converted to f32 let
+    # the TPU compiler skip the bf16 rounding in some fusions and not
+    # others, so the decode step fused with the sampler (overlap) drew
+    # other tokens than the decode step and the sampler apart.
+    return jnp.matmul(x, head, preferred_element_type=jnp.float32)
 
 
 def _embed(params, cfg, tokens, visual_embeds=None, pos_offset=0,

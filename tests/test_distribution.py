@@ -129,7 +129,6 @@ def test_dryrun_cell_lowers_on_multipod_mesh():
     import jax
     from repro.configs import get_config
     from repro.configs.base import ShapeCell
-    from repro.core.compat import cost_analysis
     from repro.launch.dryrun import build_lowerable
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
@@ -139,7 +138,7 @@ def test_dryrun_cell_lowers_on_multipod_mesh():
         with mesh:
             fn, args = build_lowerable(cfg, cell, mesh)
             compiled = fn.lower(*args).compile()
-            assert cost_analysis(compiled)["flops"] > 0
+            assert compiled.cost_analysis()["flops"] > 0
     print("dryrun lowering ok")
     """)
 
@@ -148,7 +147,6 @@ def test_compressed_psum_shard_map():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
     from repro.launch.mesh import make_mesh
     from repro.optim.grad_compression import compressed_psum
     mesh = make_mesh((8,), ("data",))
@@ -160,7 +158,7 @@ def test_compressed_psum_shard_map():
         return (total / n)[None], new_res[None]
 
     with mesh:
-        mean, _ = jax.jit(shard_map(
+        mean, _ = jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=(P("data", None), P("data", None)),
             out_specs=(P("data", None), P("data", None))))(
                 g, jnp.zeros_like(g))

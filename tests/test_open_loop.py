@@ -31,6 +31,7 @@ adversarial ones:
 """
 
 import collections
+import itertools
 import os
 import subprocess
 import sys
@@ -436,7 +437,9 @@ def test_replica_busy_clock_survives_wall_jump(rng, monkeypatch):
     slew) cannot produce negative busy/wait intervals. time.time is
     patched to run backwards; telemetry must not notice."""
     cfg, model, params = _smoke()
-    jumpy = iter(np.arange(1e9, 1e9 - 500, -7.3))
+    # steps backward forever: every caller of the process-wide
+    # time.time (jax's own included) can read it without running it dry
+    jumpy = itertools.count(1e9, -7.3)
     monkeypatch.setattr(replica_mod.time, "time",
                         lambda: float(next(jumpy)))
     rset = ReplicaSet(model, params, EngineConfig(
