@@ -1,0 +1,7 @@
+"""paged_decode_roofline.open: ``paged_decode_roofline`` in an open-loop
+cell, where the decode step sets the gaps between tokens
+(``itl_p95_ms.open``) rather than a closed loop's throughput."""
+
+from chip import harness
+
+read = harness.reader("paged_decode_roofline")
