@@ -151,6 +151,11 @@ class RequestHandle:
         sampled token; their difference is the request's TTFT,
         aggregated into p50/p95/p99 by ``latency_stats`` (surfaced via
         ``Engine.stats()["latency"]`` and ``ReplicaSet.stats()``).
+    t_admitted : float or None
+        Monotonic stamp when the request first left the queue for a
+        slot (a preempted request keeps it): ``t_admitted - t_submit``
+        is its queue wait, ``t_first_token - t_admitted`` its admission
+        to first token.
     t_tokens : list of float
         Monotonic stamp per *sampled* token (stripped stop tokens
         included — the stream advanced even though nothing was
@@ -177,6 +182,7 @@ class RequestHandle:
     # latency telemetry: stamped at submission / first sampled token /
     # every sampled token (monotonic clock throughout)
     t_submit: float = dataclasses.field(default_factory=time.monotonic)
+    t_admitted: Optional[float] = None
     t_first_token: Optional[float] = None
     t_tokens: list[float] = dataclasses.field(default_factory=list)
     # internal: RNG stream position (== tokens sampled; differs from

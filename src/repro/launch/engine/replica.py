@@ -32,12 +32,10 @@ bench reports aggregate *capacity* (sum of per-replica-clock rates),
 the number parallel hardware would sustain. Busy clocks are stamped
 with ``time.monotonic()`` at the step dispatch/return boundaries
 (never ``time.time()``, which can jump under NTP slew and is not an
-interval clock); the finer-grained device-occupancy clock lives in the
-paged backend itself (``stats()['device_s']``, a non-overlapping
-interval union across dispatch→fetch windows) so that with
-``overlap=True`` the in-flight device call is not double-counted
-across consecutive steps. ``step_workers > 1`` opts into
-thread-parallel stepping. An honest accounting of when that helps:
+interval clock); what the host waits on inside a step shows in the
+engine's ``scheduler.fetch`` spans (engine/tracing.py), and the
+device's own busy time in a profiler trace. ``step_workers > 1`` opts
+into thread-parallel stepping. An honest accounting of when that helps:
 the step loop is host-Python-heavy (dispatch bookkeeping, numpy
 mirrors, jit-call argument marshalling all hold the GIL) and only the
 blocking device fetch releases it, so threads pay GIL ping-pong on
@@ -323,7 +321,6 @@ class ReplicaSet:
             "ttft": self._ttft_stats(),
             "latency": api.latency_stats(
                 list(self.finished) + list(self._by_uid.values())),
-            "device_s": [p.get("device_s", 0.0) for p in per],
             # aggregate views the bench / leak checks read
             "mean_active_slots": sum(p["mean_active_slots"] for p in per),
             "cache_utilization": live / max(cap, 1),

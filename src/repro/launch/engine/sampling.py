@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.engine import tracing
+
 
 def fold_seed(seed: int) -> int:
     """Fold an arbitrary Python int seed into the non-negative int32
@@ -204,13 +206,17 @@ class SlotSampler:
     def sample_one(self, slot: int, row_logits):
         """Sample for ONE slot (prefill admission) from the parameters
         just installed — same streams as the batch path, no duplicate
-        parameter marshalling. row_logits: (1, V)."""
+        parameter marshalling. row_logits: (1, V). A sampled row takes
+        one device round trip (a ``scheduler.fetch`` span)."""
         if self.temps[slot] <= 0.0:
             return int(np.argmax(np.asarray(row_logits)[0]))
-        sl = slice(slot, slot + 1)
-        toks = sample_tokens(row_logits, jnp.asarray(self.seeds[sl]),
-                             jnp.asarray(self.steps[sl]),
-                             jnp.asarray(self.temps[sl]),
-                             jnp.asarray(self.top_ks[sl]),
-                             jnp.asarray(self.top_ps[sl]))
-        return int(np.asarray(toks)[0])
+        with tracing.span("scheduler.fetch") as sp:
+            if sp:
+                sp.set(what="first_token")
+            sl = slice(slot, slot + 1)
+            toks = sample_tokens(row_logits, jnp.asarray(self.seeds[sl]),
+                                 jnp.asarray(self.steps[sl]),
+                                 jnp.asarray(self.temps[sl]),
+                                 jnp.asarray(self.top_ks[sl]),
+                                 jnp.asarray(self.top_ps[sl]))
+            return int(np.asarray(toks)[0])

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import time
 from typing import Optional
 
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch import sharding as shlib
+from repro.launch.engine import tracing
 from repro.launch.engine.api import (EngineConfig, RequestHandle,
                                      RequestOutput, prefill_bucket,
                                      register_sample)
@@ -70,11 +72,9 @@ class _Pending:
     (overlap mode). ``rows`` records (slot, ticket) pairs so a harvest
     can discard draws whose slot retired or was re-admitted in between
     (tickets are globally monotonic — equality proves same request);
-    ``toks`` is the (num_slots,) int32 device array; ``t_dispatch``
-    feeds the non-overlapping device-busy clock."""
+    ``toks`` is the (num_slots,) int32 device array."""
     rows: list
     toks: object
-    t_dispatch: float
 
 
 class PagedBackend:
@@ -161,12 +161,6 @@ class PagedBackend:
         self._flushed: list[RequestOutput] = []
         # telemetry
         self.steps = 0
-        # Device-busy clock: union of dispatch->fetch intervals stamped
-        # with the monotonic clock at the call boundaries, so overlapped
-        # dispatch never double-counts in-flight device time (the
-        # ReplicaSet busy-clock fix — see stats()["device_s"]).
-        self.device_s = 0.0
-        self._t_fetch_done = 0.0
         self.slot_steps = 0          # active slots summed over steps
         self.block_token_steps = 0   # allocated token capacity x steps
         self.live_token_steps = 0    # live tokens x steps
@@ -174,6 +168,7 @@ class PagedBackend:
         self.prefill_calls = 0       # batched prefill launches
         self.prefill_reqs = 0        # requests prefilled (>= calls)
         self.prefill_tokens = 0      # real tokens computed at admission
+        self.prefill_computed_tokens = 0  # the same, with batch/bucket pad
         self.prefix_lookups = 0      # admissions that consulted the index
         self.prefix_hits = 0         # admissions with a non-empty match
         self.prefix_hit_tokens = 0   # prompt tokens served from cache
@@ -225,6 +220,7 @@ class PagedBackend:
         self._no_prev = np.zeros((cfg.num_slots,), bool)
         self._prefill_cache = {}
         self._suffix_cache = {}
+        self._called: set = set()    # step-program keys called so far
 
         def cow_fn(pools, src, dst):
             # duplicate physical block src into dst across every pool
@@ -279,6 +275,13 @@ class PagedBackend:
         step's sampled tokens are fetched, so host scheduling work
         hides under device compute. Token values are identical either
         way (the RNG-stream contract)."""
+        with tracing.span("scheduler.step") as sp:
+            outs = self._step()
+            if sp:
+                sp.set(rows=self.num_active)
+        return outs
+
+    def _step(self) -> list[RequestOutput]:
         outs: list[RequestOutput] = []
         self.made_progress = False
         if self._flushed:              # harvested during a migration
@@ -293,12 +296,7 @@ class PagedBackend:
         self._admit(outs)
         if self.prefill_only:
             return outs               # role-specialized: no decode here
-        self._grow_blocks()
-        active = [i for i, s in enumerate(self.slots) if s.req is not None]
-        if not active:
-            return outs
-        self._ensure_cow(active)       # may LIFO-preempt under pressure
-        active = [i for i in active if self.slots[i].req is not None]
+        active = self._make_room()
         if not active:
             return outs
         tokens = np.zeros((self.cfg.num_slots, 1), np.int32)
@@ -309,10 +307,15 @@ class PagedBackend:
         if self.arena is not None:
             args += (jnp.asarray(self.arena_ids),
                      jnp.asarray(self.enc_lengths))
-        t0 = time.monotonic()
-        logits, self.pools = self._decode(*args)
-        toks = self.sampler.sample(logits)
-        self._mark_device(t0)
+        first = self._first_call(("decode",))
+        with tracing.span("scheduler.dispatch") as sp:
+            if sp:
+                sp.set(rows=len(active), followup=False, first=first)
+            logits, self.pools = self._decode(*args)
+        with tracing.span("scheduler.fetch") as sp:
+            if sp:
+                sp.set(what="tokens")
+            toks = self.sampler.sample(logits)
         self.steps += 1
         self.slot_steps += len(active)
         self.block_token_steps += self.alloc.used_count * self.cfg.block_size
@@ -350,12 +353,7 @@ class PagedBackend:
         self._admit(outs)
         if followed:
             return outs
-        self._grow_blocks()
-        active = [i for i, s in enumerate(self.slots) if s.req is not None]
-        if not active:
-            return outs
-        self._ensure_cow(active)
-        active = [i for i in active if self.slots[i].req is not None]
+        active = self._make_room()
         if not active:
             return outs
         tokens = np.zeros((self.cfg.num_slots, 1), np.int32)
@@ -437,6 +435,7 @@ class PagedBackend:
         ``self._pending``. Lengths advance at dispatch (the fed token's
         cache write is in flight), so the harvest only registers the
         sampled values."""
+        followup = prev_toks is not None
         if prev_toks is None:         # no double buffer yet: dead feed
             if self._zero_toks is None:
                 self._zero_toks = jnp.zeros((self.cfg.num_slots,),
@@ -447,8 +446,11 @@ class PagedBackend:
                 host_tokens, prev_toks, use_prev, steps, samp)
         if self.arena is not None:
             args += (self.arena_ids, self.enc_lengths)
-        t0 = time.monotonic()
-        toks, self.pools = self._overlap_step(*args)
+        first = self._first_call(("overlap", samp is None))
+        with tracing.span("scheduler.dispatch") as sp:
+            if sp:
+                sp.set(rows=len(active), followup=followup, first=first)
+            toks, self.pools = self._overlap_step(*args)
         self.steps += 1
         self.slot_steps += len(active)
         self.block_token_steps += self.alloc.used_count * self.cfg.block_size
@@ -458,15 +460,17 @@ class PagedBackend:
             self.lengths[i] += 1
             self.live_token_steps += int(self.lengths[i])
             rows.append((i, self.slots[i].ticket))
-        self._pending = _Pending(rows, toks, t0)
+        self._pending = _Pending(rows, toks)
 
     def _harvest(self, pend: _Pending) -> list[RequestOutput]:
         """Block on an in-flight decode's token fetch and register the
         draws. Rows whose slot retired or was re-admitted since the
         dispatch (ticket mismatch) are discarded — their speculative
         cache writes landed at never-read positions."""
-        toks = np.asarray(pend.toks)        # the one blocking fetch
-        self._mark_device(pend.t_dispatch)
+        with tracing.span("scheduler.fetch") as sp:
+            if sp:
+                sp.set(what="tokens")
+            toks = np.asarray(pend.toks)    # the one blocking fetch
         outs = []
         for i, ticket in pend.rows:
             slot = self.slots[i]
@@ -489,14 +493,6 @@ class PagedBackend:
         pend, self._pending = self._pending, None
         self._flushed.extend(self._harvest(pend))
 
-    def _mark_device(self, t_dispatch: float):
-        """Account one dispatch->fetch interval into the device-busy
-        clock, unioned against the previous fetch so overlapping host
-        work never double-counts device time."""
-        t1 = time.monotonic()
-        self.device_s += t1 - max(t_dispatch, self._t_fetch_done)
-        self._t_fetch_done = t1
-
     def live_handles(self) -> list[RequestHandle]:
         """Resident + queued request handles (latency aggregation —
         see ``api.latency_stats``)."""
@@ -514,6 +510,28 @@ class PagedBackend:
             self.sampler.steps[i] = slot.req._n_sampled
             slot.last_token = tok
         return out
+
+    def _first_call(self, key) -> bool:
+        """True on the first call of the step program ``key``: the call
+        that compiles it (or loads it from the persistent cache)."""
+        new = key not in self._called
+        self._called.add(key)
+        return new
+
+    def _make_room(self) -> list[int]:
+        """Growth blocks, then private copies of shared tail blocks, for
+        this step's decode writes; either may LIFO-preempt when the pool
+        runs dry. Returns the slots still active."""
+        with tracing.span("scheduler.grow") as sp:
+            p0 = self.preemptions
+            self._grow_blocks()
+            active = [i for i, s in enumerate(self.slots)
+                      if s.req is not None]
+            self._ensure_cow(active)
+            if sp:                    # _preempt puts victims at the front
+                sp.set(preempted=[r.uid for r in itertools.islice(
+                    self.waiting, self.preemptions - p0)])
+        return [i for i in active if self.slots[i].req is not None]
 
     def _grow_blocks(self):
         """Allocate growth blocks oldest-admission-first; when the pool
@@ -731,11 +749,18 @@ class PagedBackend:
         return run
 
     def _admit(self, outs: list[RequestOutput]):
-        while self.waiting:
-            run = self._drain_bucket_run()
-            if not run:
-                return                    # FCFS: no skipping ahead
-            self._place_batch(run, outs)
+        if not self.waiting:
+            return
+        with tracing.span("scheduler.admit") as sp:
+            if sp:
+                sp.set(uids=[])
+            while self.waiting:
+                run = self._drain_bucket_run()
+                if not run:
+                    return                # FCFS: no skipping ahead
+                if sp:
+                    sp.attrs["uids"] += [r[0].uid for r in run]
+                self._place_batch(run, outs)
 
     def _place_batch(self, run, outs: list[RequestOutput]):
         """Admit one drained run (entries all share one admission key):
@@ -751,7 +776,10 @@ class PagedBackend:
         bs = self.cfg.block_size
         free_slots = [i for i, s in enumerate(self.slots) if s.req is None]
         rows = []                          # (slot, req, cached, S, ids)
+        now = time.monotonic()
         for req, m, cached, S in run:
+            if req.t_admitted is None:     # a resume keeps its first stamp
+                req.t_admitted = now
             nbp = paged_kv.blocks_for(S, bs)
             # matched blocks were share()'d at drain time; only the
             # non-shared tail is allocated (may reclaim from the LRU,
@@ -832,6 +860,7 @@ class PagedBackend:
         i0, _, _, S0, _ = rows[0]
         W = self._suffix_bucket(S0 - self.slots[i0].shared * bs)
         fn = self._suffix_prefill(W)
+        first = self._first_call(("suffix", W))
         N = self.cfg.num_slots
         toks = np.zeros((N, W), np.int32)
         slens = np.zeros((N,), np.int32)
@@ -847,18 +876,28 @@ class PagedBackend:
             last[i] = sfx - 1
             self.lengths[i] = S
             self.prefill_tokens += sfx
-        row_logits, self.pools = fn(
-            self.params, self.pools, jnp.asarray(stable),
-            jnp.asarray(slens), jnp.asarray(toks), jnp.asarray(last))
+        with tracing.span("scheduler.prefill") as sp:
+            if sp:
+                sp.set(key=("suffix", W), computed=N * W, first=first,
+                       tokens=sum(r[3] - self.slots[r[0]].shared * bs
+                                  for r in rows))
+            row_logits, self.pools = fn(
+                self.params, self.pools, jnp.asarray(stable),
+                jnp.asarray(slens), jnp.asarray(toks), jnp.asarray(last))
         self.prefill_calls += 1
         self.prefill_reqs += len(rows)
-        return np.asarray(row_logits)      # (num_slots, V)
+        self.prefill_computed_tokens += N * W
+        with tracing.span("scheduler.fetch") as sp:
+            if sp:
+                sp.set(what="suffix_logits")
+            return np.asarray(row_logits)  # (num_slots, V)
 
     def _full_batch(self, rows):
         """Prefix miss: the PR-4 batched full prefill — one right-padded
         batch call, each row's true-length cache scattered into its
         slot. Returns slot-indexed next-token logits."""
         fn, tok_w, cache_w, Nb = self._prefill(rows[0][3], len(rows))
+        first = self._first_call(("prefill", tok_w, Nb))
         nbc = cache_w // self.cfg.block_size
         toks = np.zeros((Nb, tok_w), np.int32)
         lens = np.ones((Nb,), np.int32)    # batch fillers: harmless len 1
@@ -876,10 +915,18 @@ class PagedBackend:
         args = (self.params, self.pools, jnp.asarray(toks),
                 jnp.asarray(ids), jnp.asarray(row_of_slot),
                 jnp.asarray(valid), jnp.asarray(lens))
-        row_logits, self.pools = fn(*args)
+        with tracing.span("scheduler.prefill") as sp:
+            if sp:
+                sp.set(key=(tok_w, Nb), tokens=sum(r[3] for r in rows),
+                       computed=Nb * tok_w, first=first)
+            row_logits, self.pools = fn(*args)
         self.prefill_calls += 1
         self.prefill_reqs += len(rows)
-        row_logits = np.asarray(row_logits)  # (Nb, V): per-row pos S-1
+        self.prefill_computed_tokens += Nb * tok_w
+        with tracing.span("scheduler.fetch") as sp:
+            if sp:
+                sp.set(what="prefill_logits")
+            row_logits = np.asarray(row_logits)  # (Nb, V): row pos S-1
         out = np.zeros((self.cfg.num_slots,) + row_logits.shape[1:],
                        row_logits.dtype)
         for r, (i, *_rest) in enumerate(rows):
@@ -917,6 +964,7 @@ class PagedBackend:
         Nb = min(1 << max(len(rows) - 1, 0).bit_length(),
                  self.cfg.num_slots)
         fn = self._encdec_prefill(tok_w, Fb, Nb)
+        first = self._first_call(("encdec", tok_w, Fb, Nb))
         nbc = paged_kv.blocks_for(tok_w, bs)
         d = self.model.cfg.d_model
         toks = np.zeros((Nb, tok_w), np.int32)
@@ -936,13 +984,22 @@ class PagedBackend:
             aids[r] = self.arena_ids[i]
             self.lengths[i] = S
             self.prefill_tokens += S
-        row_logits, self.pools = fn(
-            self.params, self.pools, jnp.asarray(toks),
-            jnp.asarray(frames), jnp.asarray(enc_lens),
-            jnp.asarray(lens), jnp.asarray(ids), jnp.asarray(aids))
+        with tracing.span("scheduler.prefill") as sp:
+            if sp:
+                sp.set(key=("encdec", tok_w, Fb, Nb),
+                       tokens=sum(r[3] for r in rows),
+                       computed=Nb * tok_w, first=first)
+            row_logits, self.pools = fn(
+                self.params, self.pools, jnp.asarray(toks),
+                jnp.asarray(frames), jnp.asarray(enc_lens),
+                jnp.asarray(lens), jnp.asarray(ids), jnp.asarray(aids))
         self.prefill_calls += 1
         self.prefill_reqs += len(rows)
-        row_logits = np.asarray(row_logits)    # (Nb, V)
+        self.prefill_computed_tokens += Nb * tok_w
+        with tracing.span("scheduler.fetch") as sp:
+            if sp:
+                sp.set(what="prefill_logits")
+            row_logits = np.asarray(row_logits)    # (Nb, V)
         out = np.zeros((self.cfg.num_slots,) + row_logits.shape[1:],
                        row_logits.dtype)
         for r, (i, *_rest) in enumerate(rows):
@@ -1168,9 +1225,9 @@ class PagedBackend:
         self.finished.clear()
         self.steps = self.slot_steps = 0
         self.block_token_steps = self.live_token_steps = 0
-        self.device_s = 0.0
         self.preemptions = 0
         self.prefill_calls = self.prefill_reqs = self.prefill_tokens = 0
+        self.prefill_computed_tokens = 0
         self.prefix_lookups = self.prefix_hits = 0
         self.prefix_hit_tokens = 0
         self.cow_copies = self.prefix_evictions = 0
@@ -1184,7 +1241,6 @@ class PagedBackend:
             "mean_active_slots": self.slot_steps / max(self.steps, 1),
             "cache_utilization": self.live_token_steps / cap,
             "overlap": bool(self.cfg.overlap),
-            "device_s": self.device_s,
             "blocks_free": self.alloc.free_count,
             "blocks_used": self.alloc.used_count,
             "preemptions": self.preemptions,
@@ -1192,6 +1248,7 @@ class PagedBackend:
             "prefill_calls": self.prefill_calls,
             "prefill_reqs": self.prefill_reqs,
             "prefill_tokens": self.prefill_tokens,
+            "prefill_computed_tokens": self.prefill_computed_tokens,
             "bucketed_prefill": self.ragged_prefill,
             "prefix_cache": {
                 "enabled": self.prefix is not None,

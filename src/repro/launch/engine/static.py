@@ -20,6 +20,7 @@ instead (exact prefill, no pad tokens ever enter the recurrence).
 from __future__ import annotations
 
 import collections
+import time
 from typing import Optional
 
 import jax
@@ -169,7 +170,9 @@ class StaticBackend:
         self.batches += 1
         self.lengths[:] = lens
         self.last[:] = 0
+        now = time.monotonic()
         for i, r in enumerate(reqs):
+            r.t_admitted = now
             self.batch[i] = r
             self.live[i] = True
             self.sampler.install(i, r.sampling, 0)

@@ -24,8 +24,9 @@ adversarial ones:
      random op interleavings always satisfy ``check_invariant`` and
      owned ⊎ LRU ⊎ free partitions every non-null block;
   6. telemetry clocks: ReplicaSet busy/wait clocks survive wall-clock
-     jumps (monotonic stamps), the paged backend's ``device_s``
-     interval union stays inside the step wall time under overlap;
+     jumps (monotonic stamps), the union of the paged backend's
+     ``scheduler.fetch`` spans stays inside the run's wall time under
+     overlap;
   7. a multi-device subprocess run of the overlap identity (mesh-
      sharded pools change WHERE tensors live, never WHAT comes out).
 """
@@ -458,10 +459,12 @@ def test_replica_busy_clock_survives_wall_jump(rng, monkeypatch):
 
 
 def test_device_clock_interval_union_under_overlap(rng):
-    """``device_s`` is a union of dispatch->fetch intervals: with
-    overlap ON, consecutive in-flight windows must not double-count —
-    the device clock stays within the total wall time of the run."""
+    """The host's blocking waits on the device are the
+    ``scheduler.fetch`` spans: with overlap ON their union is positive
+    and lies within the run's wall time, each span inside it."""
     import time as _time
+
+    from repro.launch.engine import tracing
 
     cfg, model, params = _smoke()
     eng = Engine(model, params, EngineConfig(
@@ -471,12 +474,20 @@ def test_device_clock_interval_union_under_overlap(rng):
                for L in (5, 8, 6)]
     eng.generate(prompts, SamplingParams(max_tokens=8))  # warm compiles
     eng.backend.reset_telemetry()
-    t0 = _time.monotonic()
-    eng.generate(prompts, SamplingParams(max_tokens=8))
-    wall = _time.monotonic() - t0
+    with tracing.record() as spans:
+        t0 = _time.monotonic()
+        eng.generate(prompts, SamplingParams(max_tokens=8))
+        t1 = _time.monotonic()
+    fetch = sorted((s.start, s.end) for s in spans
+                   if s.name == "scheduler.fetch")
+    union, end = 0.0, t0
+    for s, e in fetch:
+        assert t0 <= s <= e <= t1
+        union += max(0.0, e - max(s, end))
+        end = max(end, e)
     st = eng.stats()
     assert st["overlap"] is True
-    assert 0.0 < st["device_s"] <= wall
+    assert 0.0 < union <= t1 - t0
     assert st["latency"]["tpot"]["count"] == len(prompts)
 
 
