@@ -184,7 +184,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
 
 
 def paged_attention(q, pool, block_table, lengths, *, mode="decode",
-                    window=None, scale=None, kernel_mode="auto",
+                    layer=None, window=None, scale=None, kernel_mode="auto",
                     sharding=None, tp_axis="model", kv_format=None,
                     interpret=False):
     """ONE entry point for paged attention over a pool dict.
@@ -200,6 +200,10 @@ def paged_attention(q, pool, block_table, lengths, *, mode="decode",
     * ``pool`` — the per-layer pool dict ``{"k", "v"}``; a quantized
       pool also carries ``k_scale``/``v_scale`` (NB, BS, Hkv) f32
       leaves, detected here and fused into whichever backend runs.
+    * ``layer`` — decode only: an int32 scalar that selects one layer
+      of a STACKED pool dict (every leaf with a leading layer axis,
+      ``(L, NB, BS, Hkv, D)``), which the kernel reads in place; None
+      for a one-layer pool.
     * ``sharding`` — None for single-device, or an object with ``mesh``
       / ``tp_axis`` attributes (e.g. ``ShardCtx``) for the head-sharded
       shard_map path (scales shard over Hkv with the payload).
@@ -216,6 +220,11 @@ def paged_attention(q, pool, block_table, lengths, *, mode="decode",
     """
     if mode not in ("decode", "verify"):
         raise ValueError(f"mode must be 'decode' or 'verify', got {mode!r}")
+    decode = mode == "decode"
+    if layer is not None and not decode:
+        raise ValueError("a layer index reads a stacked pool in decode "
+                         "mode only")
+    stacked = {"layer": layer} if decode else {}
     k_pool, v_pool = pool["k"], pool["v"]
     k_scale, v_scale = pool.get("k_scale"), pool.get("v_scale")
     D = q.shape[-1]
@@ -227,7 +236,6 @@ def paged_attention(q, pool, block_table, lengths, *, mode="decode",
         q = jnp.pad(q, widths)
     use, interp = _use_pallas(kernel_mode)
     interp = interp or interpret
-    decode = mode == "decode"
     if sharding is not None:
         if not use and not interp:
             attend = _ref.paged_decode_attention if decode \
@@ -241,18 +249,18 @@ def paged_attention(q, pool, block_table, lengths, *, mode="decode",
                  mesh=sharding.mesh,
                  tp_axis=getattr(sharding, "tp_axis", tp_axis),
                  window=window, scale=scale, attend=attend,
-                 k_scale=k_scale, v_scale=v_scale)
+                 k_scale=k_scale, v_scale=v_scale, **stacked)
     elif not use and not interp:
         fn = _ref.paged_decode_attention if decode \
             else _ref.paged_verify_attention
         out = fn(q, k_pool, v_pool, block_table, lengths, window=window,
-                 scale=scale, k_scale=k_scale, v_scale=v_scale)
+                 scale=scale, k_scale=k_scale, v_scale=v_scale, **stacked)
     else:
         fn = paged_decode_attention_pallas if decode \
             else paged_verify_attention_pallas
         out = fn(q, k_pool, v_pool, block_table, lengths, window=window,
                  scale=scale, k_scale=k_scale, v_scale=v_scale,
-                 interpret=interp)
+                 interpret=interp, **stacked)
     return out[..., :D] if Dp != D else out
 
 

@@ -38,19 +38,22 @@ _MEGACORE = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
-def _dequant(ref, scale_ref):
+def _dequant(ref, scale_ref, lead=1):
     """Load one (BS, Hkv, D) block in f32, fusing the per-(token, head)
     dequant multiply when the pool carries int8/fp8 payload + scales —
     the fp copy of the block exists only in VMEM registers, never in
-    HBM."""
-    x = ref[0].astype(jnp.float32)
+    HBM. ``lead`` leading unit block dims (the block, and the layer of a
+    stacked pool) are indexed away."""
+    x = ref[(0,) * lead].astype(jnp.float32)
     if scale_ref is not None:
-        x = x * scale_ref[0][..., None]                 # (BS, Hkv, 1)
+        s = scale_ref[(0,) * lead]                      # (BS, Hkv)
+        # a one-head block's (BS, 1) scales already match its (BS, D)
+        x = x * (s if s.ndim == x.ndim else s[..., None])
     return x
 
 
-def _pa_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
-               hkv, group, nb, quantized):
+def _pa_kernel(bt_ref, len_ref, layer_ref, *refs, scale, window,
+               block_size, hkv, group, nb, quantized):
     if quantized:
         (q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs
@@ -77,9 +80,10 @@ def _pa_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
     def _block():
         hq = hkv * group
         q = q_ref[0].astype(jnp.float32)                # (Hq, D)
-        k = _dequant(k_ref, ks_ref)                     # (BS, Hkv, D)
-        v = _dequant(v_ref, vs_ref)
         d = q.shape[-1]
+        # (BS, Hkv, D); a one-head pool's blocks come as (BS, D)
+        k = _dequant(k_ref, ks_ref, 2).reshape(block_size, hkv, d)
+        v = _dequant(v_ref, vs_ref, 2).reshape(block_size, hkv, d)
         qg = q.reshape(hkv, group, d)
         kt = k.transpose(1, 0, 2)                       # (Hkv, BS, D)
         vt = v.transpose(1, 0, 2)
@@ -113,40 +117,65 @@ def _pa_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
 
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, lengths, *,
-                                  window=None, scale=None, k_scale=None,
-                                  v_scale=None, interpret=False):
-    """q: (B, Hq, D); pools: (NB, BS, Hkv, D); block_table: (B, NBMAX);
-    lengths: (B,) valid tokens incl. the current one; ``k_scale`` /
-    ``v_scale``: (NB, BS, Hkv) f32 dequant scales for int8/fp8 pools
-    (None = fp pool), DMA'd per block through the same prefetched index
-    map as the payload and applied in VMEM. -> (B, Hq, D)."""
+                                  layer=None, window=None, scale=None,
+                                  k_scale=None, v_scale=None,
+                                  interpret=False):
+    """q: (B, Hq, D); pools: the stacked (L, NB, BS, Hkv, D) pools of
+    every layer, read at ``layer`` (a traced int32 scalar), or one
+    layer's (NB, BS, Hkv, D) pool (``layer`` ignored); block_table:
+    (B, NBMAX); lengths: (B,) valid tokens incl. the current one;
+    ``k_scale`` / ``v_scale``: the matching (L, NB, BS, Hkv) or
+    (NB, BS, Hkv) f32 dequant scales for int8/fp8 pools (None = fp
+    pool), DMA'd per block through the same prefetched index map as the
+    payload and applied in VMEM. -> (B, Hq, D).
+
+    The layer index is a scalar-prefetch operand, so the kernel DMAs
+    the blocks of one layer straight out of the stack: the decode step
+    carries the whole pool through its layer scan and never slices a
+    layer out of it."""
+    if k_pool.ndim == 4:                # one layer: a stack of one
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
     B, Hq, D = q.shape
-    _, BS, Hkv, _ = k_pool.shape
+    L, NB, BS, Hkv, _ = k_pool.shape
     group = Hq // Hkv
     nbmax = block_table.shape[1]
     scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(D))
     quantized = k_scale is not None
+    # One kv head (a head-sharded pool with n_kv_heads == tp): on the
+    # chip the payload's layout drops the unit head axis, and an operand
+    # that kept it would be copied whole, each layer, into the kernel's
+    # layout. Its blocks go in as (BS, D). The scales keep the axis:
+    # a (BS,) block of an (L, NB, BS) array does not lower.
+    heads = () if Hkv == 1 else (Hkv,)
+    k_pool, v_pool = (p.reshape((L, NB, BS) + heads + (D,))
+                      for p in (k_pool, v_pool))
 
-    def kv_map(b, i, bt, lens):
-        return (bt[b, i], I0, I0, I0)
+    def kv_map(b, i, bt, lens, lyr):
+        return (lyr[0], bt[b, i]) + (I0,) * (1 + len(heads) + 1)
 
-    def scale_map(b, i, bt, lens):
-        return (bt[b, i], I0, I0)
+    def scale_map(b, i, bt, lens, lyr):
+        return (lyr[0], bt[b, i], I0, I0)
+
+    def q_map(b, i, bt, lens, lyr):
+        return (b, I0, I0)
 
     in_specs = [
-        pl.BlockSpec((1, Hq, D), lambda b, i, bt, lens: (b, I0, I0)),
-        pl.BlockSpec((1, BS, Hkv, D), kv_map),
-        pl.BlockSpec((1, BS, Hkv, D), kv_map),
+        pl.BlockSpec((1, Hq, D), q_map),
+        pl.BlockSpec((1, 1, BS) + heads + (D,), kv_map),
+        pl.BlockSpec((1, 1, BS) + heads + (D,), kv_map),
     ]
     operands = [q, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, BS, Hkv), scale_map)] * 2
+        in_specs += [pl.BlockSpec((1, 1, BS, Hkv), scale_map)] * 2
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nbmax),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, i, bt, lens: (b, I0, I0)),
+        out_specs=pl.BlockSpec((1, Hq, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),    # running max
             pltpu.VMEM((Hq, 1), jnp.float32),    # running sum
@@ -162,7 +191,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, lengths, *,
         compiler_params=_MEGACORE,
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      *operands)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
 
 
 def _pv_kernel(bt_ref, len_ref, *refs, scale, window, block_size,
@@ -359,9 +388,9 @@ def paged_verify_attention_headshard(q, k_pool, v_pool, block_table,
 
 def paged_decode_attention_headshard(q, k_pool, v_pool, block_table,
                                      lengths, *, mesh, tp_axis="model",
-                                     window=None, scale=None, attend=None,
-                                     k_scale=None, v_scale=None,
-                                     interpret=False):
+                                     layer=None, window=None, scale=None,
+                                     attend=None, k_scale=None,
+                                     v_scale=None, interpret=False):
     """Multi-device paged decode attention over a HEAD-sharded pool.
 
     The ``decode_seq_shard`` idea applied to the pool layout (the
@@ -374,12 +403,13 @@ def paged_decode_attention_headshard(q, k_pool, v_pool, block_table,
     and the sharded output needs NO collective; no pool byte ever
     crosses the interconnect.
 
-    q: (B, Hq, D) sharded over Hq; pools: (NB, BS, Hkv, D) sharded over
-    Hkv; ``k_scale``/``v_scale``: (NB, BS, Hkv) f32 dequant scales for
-    quantized pools, sharded over Hkv alongside the payload; requires
-    Hq % |tp| == 0 and Hkv % |tp| == 0 (group alignment then holds
-    automatically — see ``paged_kv.head_shard_ok``). ``attend`` is the
-    per-shard op; defaults to the Pallas kernel.
+    q: (B, Hq, D) sharded over Hq; pools: (NB, BS, Hkv, D), or the
+    stacked (L, NB, BS, Hkv, D) read at ``layer``, sharded over Hkv
+    (the layer axis stays whole); ``k_scale``/``v_scale``: the matching
+    f32 dequant scales for quantized pools, sharded over Hkv alongside
+    the payload; requires Hq % |tp| == 0 and Hkv % |tp| == 0 (group
+    alignment then holds automatically — see ``paged_kv.head_shard_ok``).
+    ``attend`` is the per-shard op; defaults to the Pallas kernel.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -387,21 +417,21 @@ def paged_decode_attention_headshard(q, k_pool, v_pool, block_table,
         attend = functools.partial(paged_decode_attention_pallas,
                                    interpret=interpret)
     tp = tp_axis
-    in_specs = (P(None, tp, None), P(None, None, tp, None),
-                P(None, None, tp, None), P(None, None), P(None))
+    pool_spec = P(*(None,) * (k_pool.ndim - 2), tp, None)
+    in_specs = (P(None, tp, None), pool_spec, pool_spec, P(None, None),
+                P(None), P())
     operands = (q, k_pool, v_pool, block_table.astype(jnp.int32),
-                lengths.astype(jnp.int32))
-
-    if k_scale is None:
-        def local(qv, kp, vp, bt, ln):
-            return attend(qv, kp, vp, bt, ln, window=window, scale=scale)
-    else:
-        in_specs += (P(None, None, tp), P(None, None, tp))
+                lengths.astype(jnp.int32),
+                jnp.asarray(0 if layer is None else layer, jnp.int32))
+    if k_scale is not None:
+        scale_spec = P(*(None,) * (k_scale.ndim - 1), tp)
+        in_specs += (scale_spec, scale_spec)
         operands += (k_scale, v_scale)
 
-        def local(qv, kp, vp, bt, ln, ks, vs):
-            return attend(qv, kp, vp, bt, ln, window=window, scale=scale,
-                          k_scale=ks, v_scale=vs)
+    def local(qv, kp, vp, bt, ln, ly, *scales):
+        ks, vs = scales if scales else (None, None)
+        return attend(qv, kp, vp, bt, ln, layer=ly, window=window,
+                      scale=scale, k_scale=ks, v_scale=vs)
 
     # check_vma=False: pallas_call's out_shape carries no per-axis
     # variance, which the checked shard_map would demand

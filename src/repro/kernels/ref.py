@@ -111,38 +111,45 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
 # ---------------------------------------------------------------------------
 
 
-def _gather_dequant(pool, scale_pool, block_table, B, S, Hkv, D):
+def _gather_dequant(pool, scale_pool, block_table, B, S, Hkv, D,
+                    layer=None):
     """Gather pool blocks into (B, S, Hkv, D) f32 sequences, applying the
-    per-(token, head) dequant scales when the pool is quantized."""
-    x = pool[block_table].reshape(B, S, Hkv, D).astype(jnp.float32)
+    per-(token, head) dequant scales when the pool is quantized. With
+    ``layer`` the pools are layer stacks, gathered at that layer."""
+    idx = block_table if layer is None else (layer, block_table)
+    x = pool[idx].reshape(B, S, Hkv, D).astype(jnp.float32)
     if scale_pool is not None:
-        x = x * scale_pool[block_table].reshape(B, S, Hkv)[..., None]
+        x = x * scale_pool[idx].reshape(B, S, Hkv)[..., None]
     return x
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
-                           window=None, scale=None, k_scale=None,
-                           v_scale=None):
+                           layer=None, window=None, scale=None,
+                           k_scale=None, v_scale=None):
     """Oracle single-token decode attention over a block-paged KV cache.
 
     q: (B, Hq, D) — the query for the token at position ``lengths[b] - 1``.
-    k_pool, v_pool: (NB, BS, Hkv, D) — shared pool of BS-token blocks.
+    k_pool, v_pool: (NB, BS, Hkv, D) — shared pool of BS-token blocks;
+    or the stacked (L, NB, BS, Hkv, D) pools of every layer, read at
+    ``layer`` (an int32 scalar; ignored for a one-layer pool).
     block_table: (B, NBMAX) int32 — per-sequence logical->physical block map
     (entries past a sequence's last block may hold any in-range id).
     lengths: (B,) int32 — valid tokens per sequence (including the current
     token, whose K/V must already be written to the pool).
     ``window`` restricts attention to the last ``window`` positions (SWA).
-    ``k_scale``/``v_scale``: (NB, BS, Hkv) f32 dequant scales when the
-    pool stores int8/fp8 payloads (None = fp pool, historical math).
-    Returns (B, Hq, D) in q.dtype.
+    ``k_scale``/``v_scale``: the pools' f32 dequant scales, (NB, BS, Hkv)
+    or stacked (L, NB, BS, Hkv), when the pool stores int8/fp8 payloads
+    (None = fp pool, historical math). Returns (B, Hq, D) in q.dtype.
     """
     B, Hq, D = q.shape
-    _, BS, Hkv, _ = k_pool.shape
+    BS, Hkv = k_pool.shape[-3:-1]
+    if k_pool.ndim == 4:
+        layer = None
     group = Hq // Hkv
     scale = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
     S = block_table.shape[1] * BS
-    k = _gather_dequant(k_pool, k_scale, block_table, B, S, Hkv, D)
-    v = _gather_dequant(v_pool, v_scale, block_table, B, S, Hkv, D)
+    k = _gather_dequant(k_pool, k_scale, block_table, B, S, Hkv, D, layer)
+    v = _gather_dequant(v_pool, v_scale, block_table, B, S, Hkv, D, layer)
     kx = jnp.repeat(k.transpose(0, 2, 1, 3), group, axis=1)  # (B, Hq, S, D)
     vx = jnp.repeat(v.transpose(0, 2, 1, 3), group, axis=1)
     logits = jnp.einsum("bhd,bhsd->bhs", q.astype(jnp.float32),

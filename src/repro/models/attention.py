@@ -263,13 +263,18 @@ def ring_from_prefill(kv, size, length):
 
 def decode_attend_paged(params, cfg, x, pool, block_table, lengths, *,
                         window=None, mrope_positions=None,
-                        kernel_mode="auto", shard=None, kv_spec=None):
+                        kernel_mode="auto", shard=None, kv_spec=None,
+                        layer=None):
     """Single-token decode against a block-paged KV pool.
 
     x: (B, 1, d); pool: {"k","v"} of (NB, BS, Hkv, D) (plus
     ``k_scale``/``v_scale`` leaves when ``kv_spec`` is a quantized
     ``paged_kv.PoolSpec`` — the new row is quantized at this write
-    frontier and dequant fuses into the kernel); block_table:
+    frontier and dequant fuses into the kernel). With ``layer`` (an
+    int32 scalar) every pool leaf is the stack of all layers,
+    (L, NB, BS, Hkv, D): the row is written at ``[layer, phys, off]``
+    and the kernel reads that layer in place, so no layer's pool is
+    sliced out of the stack or written back into it. block_table:
     (B, NBMAX) int32; lengths: (B,) tokens already cached per slot — the
     new token lands at position ``lengths[b]``, whose destination block
     ``block_table[b, lengths[b] // BS]`` the scheduler must have allocated
@@ -283,7 +288,7 @@ def decode_attend_paged(params, cfg, x, pool, block_table, lengths, *,
 
     B = x.shape[0]
     hq, hd = cfg.n_heads, cfg.head_dim
-    bs = pool["k"].shape[1]
+    bs = pool["k"].shape[-3]
     q, k, v = _project_qkv(params, cfg, x, x)
     posb = lengths[:, None].astype(jnp.int32)
     if cfg.rope_style == "mrope":
@@ -297,11 +302,12 @@ def decode_attend_paged(params, cfg, x, pool, block_table, lengths, *,
     logical = jnp.clip(lengths // bs, 0, block_table.shape[1] - 1)
     phys = block_table[bidx, logical]
     off = lengths % bs
-    pool = write_kv_rows(pool, phys, off, k[:, 0], v[:, 0], kv_spec)
+    pool = write_kv_rows(pool, phys, off, k[:, 0], v[:, 0], kv_spec,
+                         layer=layer)
 
     out = kops.paged_attention(
         q.reshape(B, hq, hd), pool, block_table, lengths + 1,
-        mode="decode", window=window, kernel_mode=kernel_mode,
+        mode="decode", layer=layer, window=window, kernel_mode=kernel_mode,
         sharding=shard, kv_format=kv_spec)
     out = out.reshape(B, 1, hq * hd).astype(x.dtype)
     return out @ params["wo"], pool

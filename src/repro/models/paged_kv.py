@@ -565,31 +565,35 @@ def _pad_head_dim(x, hd_pool: int):
     return jnp.pad(x, widths)
 
 
-def write_kv_rows(pool, phys, off, k, v, spec: PoolSpec = None):
+def write_kv_rows(pool, phys, off, k, v, spec: PoolSpec = None,
+                  layer=None):
     """Scatter new K/V rows at the decode/verify append frontier.
 
     pool: a pool dict ({"k","v"} plus ``k_scale``/``v_scale`` when
     quantized); phys/off: integer index arrays selecting (block, slot-
     in-block) per row; k/v: (..., Hkv, D) new rows, index arrays
-    broadcasting over the leading dims. With a quantized spec the rows
-    are quantized per (row, head) and the scales land at the same
-    (phys, off) coordinates; with ``spec=None`` / bf16 this is exactly
-    the historical two-scatter update."""
+    broadcasting over the leading dims. With ``layer`` (an int32
+    scalar) every leaf is a stack over layers and the rows land at
+    ``[layer, phys, off]``, in place in the stack. With a quantized spec
+    the rows are quantized per (row, head) and the scales land at the
+    same coordinates; with ``spec=None`` / bf16 this is exactly the
+    historical two-scatter update."""
+    idx = (phys, off) if layer is None else (layer, phys, off)
     if spec is not None:
         k = _pad_head_dim(k, spec.pool_head_dim)
         v = _pad_head_dim(v, spec.pool_head_dim)
     if spec is None or not spec.quantized:
-        return dict(pool, k=pool["k"].at[phys, off].set(
+        return dict(pool, k=pool["k"].at[idx].set(
                         k.astype(pool["k"].dtype)),
-                    v=pool["v"].at[phys, off].set(
+                    v=pool["v"].at[idx].set(
                         v.astype(pool["v"].dtype)))
     kq, ks = quantize_kv(k, spec)
     vq, vs = quantize_kv(v, spec)
     return dict(pool,
-                k=pool["k"].at[phys, off].set(kq),
-                v=pool["v"].at[phys, off].set(vq),
-                k_scale=pool["k_scale"].at[phys, off].set(ks),
-                v_scale=pool["v_scale"].at[phys, off].set(vs))
+                k=pool["k"].at[idx].set(kq),
+                v=pool["v"].at[idx].set(vq),
+                k_scale=pool["k_scale"].at[idx].set(ks),
+                v_scale=pool["v_scale"].at[idx].set(vs))
 
 
 # ---------------------------------------------------------------------------

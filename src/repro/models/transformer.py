@@ -342,13 +342,15 @@ def apply_block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos,
 
 def apply_block_decode_paged(p, cfg: ModelConfig, kind: str, x, cache,
                              block_table, lengths, ctx: RunCtx,
-                             mrope_positions=None):
+                             mrope_positions=None, layer=None):
     """One-token block step with PER-SLOT positions over the paged cache.
 
     Full-attention layers attend a shared block pool via the per-sequence
     block table; windowed layers keep per-slot ring buffers (bounded state
     — paging buys nothing); SSM kinds carry per-slot recurrent state and
     are position-independent, so the stock decode applies unchanged.
+    ``layer`` (an int32 scalar) marks a full-attention ``cache`` as the
+    stack of every layer's pool, updated and read at that index.
     """
     xn = layers.apply_norm(cfg.norm, p["ln1"], x)
     if kind in ("attn", "local"):
@@ -359,7 +361,7 @@ def apply_block_decode_paged(p, cfg: ModelConfig, kind: str, x, cache,
                 mrope_positions=mrope_positions,
                 kernel_mode=ctx.kernel_mode,
                 shard=ctx.shard if ctx.decode_head_shard else None,
-                kv_spec=ctx.kv_spec)
+                kv_spec=ctx.kv_spec, layer=layer)
         else:
             out, cache = attn_lib.decode_attend_batched(
                 p["attn"], cfg, xn, cache, lengths, window=window,
@@ -491,32 +493,47 @@ def map_layer_tree(cfg: ModelConfig, fn):
             for gk, pattern, count in layer_walk(cfg)}
 
 
-def scan_groups(params, cfg: ModelConfig, x, trees, block_fn, ctx: RunCtx):
+def scan_groups(params, cfg: ModelConfig, x, trees, block_fn, ctx: RunCtx,
+                carried=None):
     """Scan ``x`` through every stacked group, threading a side tree.
 
     The shared driver behind ``decode_step`` / ``decode_step_paged`` /
     ``decode_verify_paged``: ``trees`` mirrors ``params["groups"]`` (a
     decode cache or paged pool) and ``block_fn(kind, layer_params,
     layer_tree, x) -> (x, new_layer_tree)`` is the per-layer cell.
+    Each layer's tree rides the scan as xs -> ys: sliced out of the
+    stack, updated, stacked again. A layer kind for which ``carried``
+    is true rides it whole, as carry, instead: ``block_fn`` then gets
+    the group's stack and ``layer=`` its int32 index into it, and
+    updates the stack in place.
     Returns ``(x, new_trees)`` with ``new_trees`` same-structure.
     """
     new_trees = {}
     for gk, pattern, count in layer_walk(cfg):
-        gp = params["groups"][gk]
-        gc = trees[gk]
+        keep = {f"p{pi}" for pi, kind in enumerate(pattern)
+                if carried is not None and carried(kind)}
+        stacks = {pk: t for pk, t in trees[gk].items() if pk in keep}
+        sliced = {pk: t for pk, t in trees[gk].items() if pk not in keep}
 
-        def body(xc, scanned, pattern=pattern):
-            layer_params, layer_tree = scanned
-            new_lt = {}
+        def body(carry, scanned, pattern=pattern, keep=keep):
+            xc, stacks = carry
+            layer_params, layer_tree, layer = scanned
+            stacks, new_lt = dict(stacks), {}
             for pi, kind in enumerate(pattern):
-                xc, nt = block_fn(kind, layer_params[f"p{pi}"],
-                                  layer_tree[f"p{pi}"], xc)
-                new_lt[f"p{pi}"] = nt
-            return xc, new_lt
+                pk = f"p{pi}"
+                if pk in keep:
+                    xc, stacks[pk] = block_fn(kind, layer_params[pk],
+                                              stacks[pk], xc, layer=layer)
+                else:
+                    xc, new_lt[pk] = block_fn(kind, layer_params[pk],
+                                              layer_tree[pk], xc)
+            return (xc, stacks), new_lt
 
-        x, new_gc = jax.lax.scan(body, x, (gp, gc),
-                                 unroll=True if ctx.scan_unroll else 1)
-        new_trees[gk] = new_gc
+        layer_ix = jnp.arange(count, dtype=jnp.int32)
+        (x, stacks), new_gc = jax.lax.scan(
+            body, (x, stacks), (params["groups"][gk], sliced, layer_ix),
+            unroll=True if ctx.scan_unroll else 1)
+        new_trees[gk] = {**stacks, **new_gc}
     return x, new_trees
 
 
@@ -835,11 +852,20 @@ def decode_step_paged(params, cfg: ModelConfig, pools, block_table, lengths,
             "paged decode supports decoder-only rope/none-pos models")
     x = _embed(params, cfg, tokens, shard=ctx.shard)
 
-    def block_fn(kind, lp, lc, xc):
+    def block_fn(kind, lp, lc, xc, layer=None):
         return apply_block_decode_paged(lp, cfg, kind, xc, lc,
-                                        block_table, lengths, ctx)
+                                        block_table, lengths, ctx,
+                                        layer=layer)
 
-    x, new_pools = scan_groups(params, cfg, x, pools, block_fn, ctx)
+    # Block-pool leaves ride the layer scan whole, as carry: each layer
+    # appends its rows at [layer, phys, off] and the kernel reads the
+    # stack at that index, so the step never copies a pool. As xs -> ys,
+    # every layer's pool would be sliced out of the stack, written, and
+    # stacked again. Per-slot state (windowed rings, SSM carries) is
+    # small and keeps xs -> ys.
+    x, new_pools = scan_groups(params, cfg, x, pools, block_fn, ctx,
+                               carried=lambda kind: _is_pool_kind(cfg,
+                                                                  kind))
     x = layers.apply_norm(cfg.norm, params["final_norm"], x)
     return _logits(params, cfg, x)[:, 0], new_pools
 

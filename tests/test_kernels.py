@@ -143,3 +143,38 @@ def test_property_matmul_any_shape(m, n):
                          mode="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.matmul(x, w)),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2), (4, 1)])   # MHA/GQA/MQA
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("mode", ["interpret", "ref"])
+def test_paged_decode_reads_stacked_pool_by_layer(rng, hq, hkv, kv_dtype,
+                                                  mode):
+    """Paged decode over the stacked pools of every layer, read at layer
+    l, is bit-equal to the same call on layer l's pool alone, for every
+    l: the index selects the blocks and changes no arithmetic."""
+    from repro.models import paged_kv
+
+    L, B, bs, nbmax, D = 3, 3, 4, 4, 16
+    nb = B * nbmax + 1
+    q = _randn(rng, (B, hq, D), jnp.bfloat16)
+    k = _randn(rng, (L, nb, bs, hkv, D), jnp.float32)
+    v = _randn(rng, (L, nb, bs, hkv, D), jnp.float32)
+    if kv_dtype == "bf16":
+        pool = {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
+    else:
+        spec = paged_kv.PoolSpec(kv_dtype=kv_dtype, block_size=bs,
+                                 n_kv_heads=hkv, head_dim=D)
+        (kq, ks), (vq, vs) = (paged_kv.quantize_kv(t, spec) for t in (k, v))
+        pool = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    table = jnp.asarray(rng.permutation(nb - 1)[:B * nbmax]
+                        .reshape(B, nbmax) + 1, jnp.int32)
+    lengths = jnp.asarray([5, 16, 1], jnp.int32)
+    for layer in range(L):
+        got = ops.paged_attention(q, pool, table, lengths,
+                                  layer=jnp.int32(layer), kernel_mode=mode)
+        want = ops.paged_attention(q, {n: t[layer] for n, t in pool.items()},
+                                   table, lengths, kernel_mode=mode)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32),
+                                      err_msg=f"layer {layer}")

@@ -145,6 +145,68 @@ def test_layer_decode_paged_matches_dense(rng, arch, window):
                                    err_msg=f"step {t}")
 
 
+def _decode_step_by_slices(params, cfg, pools, table, lengths, tokens,
+                           ctx):
+    """The paged decode step through ``scan_groups`` with nothing
+    carried: every layer's pool rides the layer scan as xs -> ys (sliced
+    out of the stack, written, stacked again)."""
+    from repro.models import layers, transformer
+
+    x = transformer._embed(params, cfg, tokens)
+
+    def block_fn(kind, lp, lc, xc):
+        return transformer.apply_block_decode_paged(lp, cfg, kind, xc, lc,
+                                                    table, lengths, ctx)
+
+    x, new_pools = transformer.scan_groups(params, cfg, x, pools, block_fn,
+                                           ctx)
+    x = layers.apply_norm(cfg.norm, params["final_norm"], x)
+    return transformer._logits(params, cfg, x)[:, 0], new_pools
+
+
+@pytest.mark.parametrize("arch,pattern,kv_dtype,mode", [
+    ("olmo_1b", None, "bf16", "ref"),
+    ("olmo_1b", None, "int8", "interpret"),
+    # windowed ring and RG-LRU state (xs -> ys) beside a pool (carry)
+    ("recurrentgemma_2b", ("rglru", "attn", "local"), "bf16", "ref")])
+def test_decode_step_in_place_matches_sliced_walk(rng, arch, pattern,
+                                                  kv_dtype, mode):
+    """``decode_step_paged`` (pools carried whole through the layer scan,
+    updated in place by layer index) gives logits and new pools bit-equal
+    to the walk that slices each layer's pool out of the stack."""
+    import dataclasses
+
+    cfg = get_config(arch).smoke()
+    if pattern is not None:
+        cfg = dataclasses.replace(cfg, block_pattern=pattern, n_layers=6)
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    layout = paged_kv.PagedLayout(num_slots=3, num_blocks=13, block_size=4,
+                                  max_len=16)
+    spec = None if kv_dtype == "bf16" else paged_kv.make_pool_spec(
+        cfg, layout, kv_dtype=kv_dtype)
+    ctx = RunCtx(kernel_mode=mode, kv_spec=spec)
+
+    def noise(t):
+        if t.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, t.shape), t.dtype)
+        return jnp.asarray(rng.normal(size=t.shape)).astype(t.dtype)
+
+    pools = jax.tree.map(noise, model.init_paged_cache(layout, spec))
+    table = jnp.asarray(rng.permutation(12).reshape(3, 4) + 1, jnp.int32)
+    lengths = jnp.asarray([3, 8, 13], jnp.int32)
+    tokens = jnp.asarray([[5], [7], [9]], jnp.int32)
+    got = jax.jit(lambda p, pl: model.decode_step_paged(
+        p, pl, table, lengths, tokens, ctx))(params, pools)
+    want = jax.jit(lambda p, pl: _decode_step_by_slices(
+        p, cfg, pl, table, lengths, tokens, ctx))(params, pools)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
 # -- 3. right-padded (bucketed) prefill == exact-length prefill ---------
 
 
